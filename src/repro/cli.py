@@ -240,9 +240,8 @@ def profile_main(argv=None, *, prog="python -m repro profile") -> int:
     ap.add_argument("--out", default=None,
                     help="write the speed-matrix JSON here (default: stdout)")
     ap.add_argument("--no-interpret", dest="interpret", action="store_false",
-                    default=None,
-                    help="compile the kernels instead of interpret mode "
-                         "(default: interpret off-TPU)")
+                    help="compile the kernels instead of running them in "
+                         "Pallas interpret mode (compiling needs a TPU)")
     ap.add_argument("--list", action="store_true",
                     help="list the workload catalog and exit")
     ap.add_argument("--check-schema", metavar="MATRIX.json", default=None,
@@ -755,6 +754,8 @@ COMMANDS = {
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         print(_USAGE, end="")

@@ -53,7 +53,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.sysmonitor import (S_DISABLED, S_HEALTHY, S_INIT,
                                    S_OVERLIMIT, S_UNHEALTHY)
@@ -73,6 +72,15 @@ _SC = ("dt", "p_fail", "p_err", "repair_s", "outage_s", "ck_interval",
        "th_tmp_o", "readmit_base", "readmit_cap", "ol_window", "init_dur",
        "temp_c")
 _SCI = {k: i for i, k in enumerate(_SC)}
+
+
+def _host_device():
+    """Where the kernel runs: the host CPU, on every backend.  The parity
+    contract needs IEEE binary64 elementwise math, which XLA:CPU gives; a
+    TPU has no native f64 and emulates it, so on a TPU host the kernel is
+    placed on the CPU explicitly rather than following the default device
+    (ROADMAP S4/D3)."""
+    return jax.devices("cpu")[0]
 
 
 def _compile(jitted, *args):
@@ -291,10 +299,11 @@ class XlaTickEngine:
         return cores
 
     def _run_block(self, inps: list[dict], cores: list[dict]) -> int:
-        # x64 is scoped to the engine's own traces/calls (the fleet math is
-        # float64 end to end) so the rest of the process — the float32
-        # predictor, models, serving engine — keeps jax's default dtypes
-        with enable_x64():
+        # x64 and the host placement are scoped to the engine's own
+        # traces/calls (the fleet math is float64 end to end) so the rest
+        # of the process — the float32 predictor, models, serving engine —
+        # keeps jax's default dtypes and device
+        with jax.enable_x64(True), jax.default_device(_host_device()):
             return self._run_block_x64(inps, cores)
 
     def _run_block_x64(self, inps: list[dict], cores: list[dict]) -> int:

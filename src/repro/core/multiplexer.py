@@ -46,6 +46,8 @@ class MuxConfig:
     evict_after_violations: int = 50  # SysMonitor-style overlimit -> evict
     latency_budget_s: float | None = None   # absolute end-to-end budget
     quota_frac: float = 0.4
+    # stand-in for backends that report no memory; callers on an
+    # accelerator pass the device's own bytes_limit (memory_stats())
     device_bytes: int = 16 << 30
 
 

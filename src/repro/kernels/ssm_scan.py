@@ -1,15 +1,21 @@
 """Pallas TPU chunked selective-scan kernel (Mamba hot spot in jamba).
 
-Grid (batch, n_chunks) with the chunk axis *sequential*: the SSM state
-h (d_inner_block, N) lives in VMEM scratch and is carried across chunk
-iterations (dimension_semantics=("parallel", "arbitrary")).  Within a chunk
-the first-order recurrence h_t = dA_t·h_{t-1} + dBx_t is evaluated by a
-short fori_loop over the chunk (N=16 lanes per channel; the per-step work is
-a (d_blk, N) FMA — VPU-bound, which is the true character of the Mamba scan;
-the matmuls around it stay in XLA).
+Grid (batch, d_inner/d_blk, n_chunks) with the chunk axis *sequential*: the
+SSM state h lives in VMEM scratch as (N, d_blk) — d_inner on the 128 lanes,
+the N state entries on sublanes — and is carried across chunk iterations.
+Blocking d_inner keeps the working set fixed whatever the model width
+(jamba's d_inner is 16384).  Within a chunk the first-order recurrence
+h_t = dA_t·h_{t-1} + dBx_t runs as a loop over groups of 8 timesteps: each
+group loads 8 aligned rows of dt and dt·x, unrolls the 8 steps over static
+slices and stores 8 aligned output rows, so no load or store needs a dynamic
+sub-tile offset.  The per-step work is an (N, d_blk) FMA and exp — VPU-bound,
+which is the true character of the Mamba scan; the matmuls around it stay in
+XLA.
 
-VMEM working set per program: chunk·d_blk (dt, x) + chunk·N (B, C) + d_blk·N
-(state) fp32 ≈ 0.6 MB at chunk=64, d_blk=512, N=16.
+B_t and C_t enter as (chunk, N, 1) tiles, so that B_t is an (N, 1) column
+that broadcasts along the lanes.  VMEM per program at chunk=64, d_blk=512,
+N=16: dt, dt·x and y tiles 3·128 KB, B and C tiles 2·512 KB (the unit lane
+dim pads to 128), state 32 KB; about 3 MB double-buffered.
 """
 from __future__ import annotations
 
@@ -20,37 +26,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+_GROUP = 8      # timesteps per aligned load/store (f32 sublane tile)
+_D_BLK = 512    # d_inner lanes per program
 
 
-def _ssm_kernel(dt_ref, bx_ref, c_ref, alog_ref, o_ref, h_ref, *, chunk, n_state):
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
+def _ssm_kernel(dt_ref, bx_ref, b_ref, c_ref, a_ref, o_ref, h_ref, *, chunk):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    dt = dt_ref[...][0].astype(jnp.float32)          # (chunk, d_blk)
-    bx = bx_ref[...][0].astype(jnp.float32)          # (chunk, d_blk)  = dt*x (pre-multiplied)
-    Bc = c_ref[...][0, :, 0, :]                      # (chunk, N)  B_t
-    Cc = c_ref[...][0, :, 1, :]                      # (chunk, N)  C_t
-    A = -jnp.exp(alog_ref[...].astype(jnp.float32))   # (d_blk, N)
+    A = -jnp.exp(a_ref[...])                             # (N, d_blk)
 
-    def step(t, carry):
-        h, out = carry
-        dA = jnp.exp(dt[t][:, None] * A)                       # (d_blk, N)
-        h = dA * h + bx[t][:, None] * Bc[t][None, :]
-        y_t = (h * Cc[t][None, :]).sum(axis=1)                 # (d_blk,)
-        out = jax.lax.dynamic_update_index_in_dim(out, y_t, t, 0)
-        return h, out
+    def group(g, h):
+        t0 = pl.multiple_of(g * _GROUP, _GROUP)
+        dt = dt_ref[0, pl.ds(t0, _GROUP), :]             # (8, d_blk)
+        bx = bx_ref[0, pl.ds(t0, _GROUP), :]
+        Bg = b_ref[0, pl.ds(t0, _GROUP)]                 # (8, N, 1)
+        Cg = c_ref[0, pl.ds(t0, _GROUP)]
+        ys = []
+        for i in range(_GROUP):
+            dA = jnp.exp(dt[i:i + 1, :] * A)             # (N, d_blk)
+            h = dA * h + Bg[i] * bx[i:i + 1, :]
+            ys.append((h * Cg[i]).sum(axis=0, keepdims=True))
+        o_ref[0, pl.ds(t0, _GROUP), :] = jnp.concatenate(ys, axis=0)
+        return h
 
-    h0 = h_ref[...]
-    out0 = jnp.zeros((chunk, dt.shape[1]), jnp.float32)
-    h, out = jax.lax.fori_loop(0, chunk, step, (h0, out0))
-    h_ref[...] = h
-    o_ref[...] = out.astype(o_ref.dtype)[None]
+    h_ref[...] = jax.lax.fori_loop(0, chunk // _GROUP, group, h_ref[...])
 
 
 def ssm_scan(dt: jax.Array, x: jax.Array, B_ssm: jax.Array, C_ssm: jax.Array,
@@ -63,26 +64,26 @@ def ssm_scan(dt: jax.Array, x: jax.Array, B_ssm: jax.Array, C_ssm: jax.Array,
     """
     Bsz, S, di = x.shape
     N = B_ssm.shape[-1]
-    assert S % chunk == 0
-    nck = S // chunk
-    bx = (dt * x).astype(jnp.float32)
-    bc = jnp.stack([B_ssm, C_ssm], axis=2)      # (B, S, 2, N)
+    d_blk = min(_D_BLK, di)
+    assert S % chunk == 0 and chunk % _GROUP == 0 and di % d_blk == 0, \
+        (S, chunk, di, d_blk)
+    dt = dt.astype(jnp.float32)
+    bx = dt * x.astype(jnp.float32)
+    bc = B_ssm.astype(jnp.float32)[..., None]            # (B, S, N, 1)
+    cc = C_ssm.astype(jnp.float32)[..., None]
+    a_t = A_log.astype(jnp.float32).T                    # (N, di)
 
-    kernel = functools.partial(_ssm_kernel, chunk=chunk, n_state=N)
-    out = pl.pallas_call(
-        kernel,
-        grid=(Bsz, nck),
-        in_specs=[
-            pl.BlockSpec((1, chunk, di), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, di), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 2, N), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((di, N), lambda b, c: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk, di), lambda b, c: (b, c, 0)),
+    seq_spec = pl.BlockSpec((1, chunk, d_blk), lambda b, j, c: (b, c, j))
+    col_spec = pl.BlockSpec((1, chunk, N, 1), lambda b, j, c: (b, c, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_ssm_kernel, chunk=chunk),
+        grid=(Bsz, di // d_blk, S // chunk),
+        in_specs=[seq_spec, seq_spec, col_spec, col_spec,
+                  pl.BlockSpec((N, d_blk), lambda b, j, c: (0, j))],
+        out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((Bsz, S, di), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((di, N), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((N, d_blk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(dt.astype(jnp.float32), bx, bc.astype(jnp.float32), A_log)
-    return out
+    )(dt, bx, bc, cc, a_t)

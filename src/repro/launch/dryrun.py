@@ -164,8 +164,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, dump_hlo=None,
             ma = compiled.memory_analysis()
             print(ma)
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per device
-                ca = ca[0] if ca else {}
             print({k: v for k, v in ca.items() if k in ("flops", "bytes accessed")})
             text = compiled.as_text()
             if dump_hlo:

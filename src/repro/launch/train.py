@@ -21,6 +21,7 @@ from repro.checkpoint.checkpointing import AsyncCheckpointer, latest_step, resto
 from repro.configs import ARCH_IDS, get_config
 from repro.core.errors import GracefulExit
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import init_params, make_train_step
 from repro.optim.optimizer import AdamW, AdamWConfig
@@ -115,6 +116,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
     out = run(args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
               seq=args.seq, lr=args.lr, ckpt_dir=args.ckpt_dir,
               ckpt_every=args.ckpt_every, microbatches=args.microbatches)

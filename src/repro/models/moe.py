@@ -14,14 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map (with check_vma) landed after 0.4.x; fall back to the
-# experimental module and its check_rep spelling of the same kwarg
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised on jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 from repro.sharding.context import constrain
 
 from .layers import dense_init, _ACTS
@@ -242,13 +234,13 @@ def moe_a2a_dispatch(params, x, cfg, capacity_factor: float = 1.25):
     shared = params.get("shared")
     shared_spec = (jax.tree.map(lambda _: P(), shared)
                    if shared is not None else None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), shared_spec,
                   P(batch_spec, None, None)),
         out_specs=(P(batch_spec, None, None), P(dp_spec if dp else None)),
-        **{_CHECK_KW: False})
+        check_vma=False)
     y, aux = fn(params["router"], params["w_gate"], params["w_up"],
                 params["w_down"], shared, x)
     return y, jnp.mean(aux)

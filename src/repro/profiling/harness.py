@@ -4,9 +4,10 @@ What DCGM measures on a real MuxFlow node, reproduced as a deterministic
 discrete-event emulation over *executed* workloads:
 
   * Every catalog workload is first **executed for real** (:func:`
-    repro.profiling.workloads.execute`) — Pallas kernels in interpret mode on
-    CPU — which yields an output checksum (artifact-stable proof of
-    execution) and the roofline step costs the virtual clock runs on.
+    repro.profiling.workloads.execute`) — Pallas kernels in interpret mode
+    unless the caller compiles them — which yields an output checksum
+    (artifact-stable proof of execution) and the roofline step costs the
+    virtual clock runs on.
   * Each (online, offline, SM-share) cell then runs a quantum-level device
     loop: online requests arrive on a seeded Poisson process and have strict
     priority; offline steps are non-preemptive and gated by the *actual*
@@ -175,7 +176,7 @@ class PairProfiler:
     """Profiles every online×offline catalog pair across a share sweep."""
     suite: SuiteConfig
     seed: int = 0
-    interpret: bool | None = None
+    interpret: bool = True
     catalog: dict[str, Workload] | None = None
 
     def __post_init__(self):
@@ -241,7 +242,7 @@ class PairProfiler:
 
 
 def build_speed_matrix(suite: str = "smoke", seed: int = 0,
-                       interpret: bool | None = None):
+                       interpret: bool = True):
     """Execute + profile + assemble the versioned speed-matrix artifact."""
     from repro.profiling.matrix import SpeedMatrix
     sc = SUITES[suite]

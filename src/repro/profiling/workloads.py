@@ -7,8 +7,8 @@ This module is the single metrics-sampling path (it absorbed the seed's
 removed).  A profile has two sources of truth, kept deliberately separate:
 
   * **Execution** — :func:`execute` really runs the step function (Pallas
-    kernels in interpret mode on CPU, compiled on TPU) and records an output
-    checksum plus wall-time stats.  Wall time is *measurement-only*: it
+    kernels interpreted or compiled, as the caller says) and records an
+    output checksum plus wall-time stats.  Wall time is *measurement-only*: it
     proves the workload runs and how fast, but it never enters a speed-matrix
     artifact, because artifacts must be byte-identical across runs.
   * **Cost model** — deterministic per-step cost from the declared analytic
@@ -97,16 +97,14 @@ class ExecutionRecord:
         self.profile = self.workload.profile()
 
 
-def execute(workload: Workload, *, interpret: bool | None = None,
+def execute(workload: Workload, *, interpret: bool,
             clock=time.perf_counter) -> ExecutionRecord:
     """Run ``workload`` for real: warmup, then ``steps`` timed iterations.
 
     Returns the execution record with an output checksum (rounded so the
-    float is stable) and wall stats.  ``interpret`` defaults to True off-TPU
-    so the Pallas kernels discharge on CPU."""
-    import jax
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    float is stable) and wall stats.  ``interpret`` says whether the Pallas
+    kernels run in the interpreter or compiled; it is never guessed from
+    the backend."""
     step_fn = workload.build(interpret)
     for _ in range(workload.warmup):
         step_fn()

@@ -66,9 +66,11 @@ class ServingEngine:
         self.waiting: list[ServeRequest] = []
         self.finished: list[ServeRequest] = []
         self.steps = 0
+        # the cache is donated: each step updates it in place on the device
         self._decode = jax.jit(
             lambda p, c, t, pos: forward(p, cfg, {"tokens": t},
-                                         mode="decode", cache=c, pos=pos))
+                                         mode="decode", cache=c, pos=pos),
+            donate_argnums=(1,))
 
     # -- admission ----------------------------------------------------------
     def submit(self, req: ServeRequest) -> None:

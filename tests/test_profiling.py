@@ -48,12 +48,12 @@ def test_catalog_roles_and_costs():
 
 def test_execute_runs_real_steps():
     cat = build_catalog()
-    rec = execute(cat["ssm-scan"])
+    rec = execute(cat["ssm-scan"], interpret=True)
     assert rec.steps_executed == cat["ssm-scan"].steps
     assert np.isfinite(rec.checksum) and rec.checksum != 0.0
     assert rec.wall_ms_per_step > 0
     # execution is deterministic: same seed, same checksum
-    assert execute(cat["ssm-scan"]).checksum == rec.checksum
+    assert execute(cat["ssm-scan"], interpret=True).checksum == rec.checksum
 
 
 # ------------------------------------------------------------------ harness
