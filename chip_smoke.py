@@ -335,7 +335,8 @@ def colocate_phase(seed: int, engine: ServingEngine, train_step,
                     f"{int(cfg.quota_frac * device_bytes)} bytes")
     log("colocate", f"served={st.served} p50={st.p50_ms:.2f}ms "
                     f"p99={st.p99_ms:.2f}ms offline_steps={st.offline_steps} "
-                    f"duty={st.offline_duty:.3f} oversold={st.oversold:.3f} "
+                    f"offline_time_share={st.offline_duty:.3f} "
+                    f"oversold={st.oversold:.3f} "
                     f"slo_violations={st.slo_violations} evicted={st.evicted} "
                     f"({time.perf_counter() - t:.1f}s wall)")
     check(st.served > 0 and math.isfinite(st.p99_ms), "colocate",

@@ -128,7 +128,7 @@ def main() -> None:
           f"p99={s.p99_ms:.2f}ms (base {s.base_ms:.2f}ms)")
     print(f"offline: {s.offline_steps} train steps "
           f"(loss {losses[0]:.3f} -> {losses[-1]:.3f}), "
-          f"duty={s.offline_duty:.2f}, oversold={s.oversold:.2f}")
+          f"offline time share={s.offline_duty:.2f}, oversold={s.oversold:.2f}")
     print(f"safety : evicted={s.evicted}, slo_violations={s.slo_violations}")
     gex = mux.graceful
     if gex.triggered is not None:

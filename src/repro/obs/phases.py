@@ -1,7 +1,13 @@
-"""Opt-in wall-clock profiling of the engine tick's phases.
+"""Wall-clock spans: totals, calls and the longest call per named phase.
 
-Attached to a :class:`ClusterSim` via ``attach_phases``, the profiler
-accumulates wall time per phase of the tick pipeline::
+Each phase is also a ``jax.profiler.TraceAnnotation``, so while the JAX
+profiler traces, every phase is a host span in its trace, on the same
+clock as the device's operations.  When no trace is active the annotation
+costs about a microsecond.
+
+Users: the serving engine's step (``engine.*``), the multiplexer's loop
+(``mux.control``), and, attached to a :class:`ClusterSim` via
+``attach_phases``, the fleet simulator's tick pipeline::
 
     inputs      _tick_inputs (RNG draws, profile arrays, policy surfaces)
     predict     build_weight_grid_arrays (speed-predictor weight grid)
@@ -11,44 +17,56 @@ accumulates wall time per phase of the tick pipeline::
     serving     the serving plane's lane stepping inside _account
 
 QUARANTINED: these numbers are wall clock and therefore never enter any
-deterministic artifact — they surface only in ``BENCH_sim.json`` (the
-``obs_overhead`` suite) and on stderr (``--profile-phases``).  The report's
-``obs`` section records *that* profiling ran, never its timings.
+deterministic artifact — the simulator's surface only in ``BENCH_sim.json``
+(the ``obs_overhead`` suite) and on stderr (``--profile-phases``).  The
+report's ``obs`` section records *that* profiling ran, never its timings.
 """
 from __future__ import annotations
 
 import contextlib
 import time
 
+from jax.profiler import TraceAnnotation
+
 PHASES = ("inputs", "predict", "match", "dense_core", "account", "serving")
 
 
 class PhaseProfiler:
-    """Accumulates ``(wall_s, calls)`` per named phase."""
+    """Accumulates per named phase its total wall time (``totals``), its
+    calls (``calls``) and its longest single call (``longest``)."""
 
     def __init__(self, clock=time.perf_counter):
         self.clock = clock
         self.totals: dict[str, float] = {}
         self.calls: dict[str, int] = {}
+        self.longest: dict[str, float] = {}
+
+    def reset(self) -> None:
+        """Forget every phase, so that totals cover what follows."""
+        self.totals.clear()
+        self.calls.clear()
+        self.longest.clear()
 
     @contextlib.contextmanager
     def phase(self, name: str, exclude: tuple = ()):
         """Time a block under ``name``.  ``exclude`` subtracts the growth of
         other phases timed *inside* the block (e.g. ``account`` excludes the
         nested ``serving`` slice so the two don't double-count)."""
-        t0 = self.clock()
         pre = [self.totals.get(x, 0.0) for x in exclude]
-        try:
-            yield
-        finally:
-            dt = self.clock() - t0
-            for x, p in zip(exclude, pre):
-                dt -= self.totals.get(x, 0.0) - p
-            self.add(name, dt)
+        with TraceAnnotation(name):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                dt = self.clock() - t0
+                for x, p in zip(exclude, pre):
+                    dt -= self.totals.get(x, 0.0) - p
+                self.add(name, dt)
 
     def add(self, name: str, dt: float) -> None:
         self.totals[name] = self.totals.get(name, 0.0) + dt
         self.calls[name] = self.calls.get(name, 0) + 1
+        self.longest[name] = max(self.longest.get(name, dt), dt)
 
     def total(self, name: str) -> float:
         return self.totals.get(name, 0.0)

@@ -16,6 +16,16 @@ Fixed shapes mean exactly one compiled program regardless of traffic — which
 is what makes MuxFlow's duty-cycle throttling well-behaved on TPU (no
 recompilation storms when the multiplexer squeezes offline steps between
 engine steps).
+
+Every step records four spans in ``self.phases`` (always on; each is also a
+host span in a JAX profiler trace, see ``repro.obs.phases``):
+
+  engine.admit     waiting requests into free slots
+  engine.launch    slot tokens and positions to the device, and the decode
+                   call (``jit_engine_decode`` in a trace) until it returns
+  engine.readback  the logits to a host array: the wait for the device and
+                   the copy
+  engine.sample    the per-slot loop: argmax, bookkeeping, retirement
 """
 from __future__ import annotations
 
@@ -27,6 +37,7 @@ import numpy as np
 
 from repro.models import init_cache
 from repro.models.model import ModelConfig, forward
+from repro.obs.phases import PhaseProfiler
 
 
 @dataclasses.dataclass
@@ -36,7 +47,6 @@ class ServeRequest:
     max_new_tokens: int
     arrival: float = 0.0
     output: list = dataclasses.field(default_factory=list)
-    done_at: float | None = None
 
 
 @dataclasses.dataclass
@@ -66,11 +76,14 @@ class ServingEngine:
         self.waiting: list[ServeRequest] = []
         self.finished: list[ServeRequest] = []
         self.steps = 0
+        self.phases = PhaseProfiler()
+
+        def engine_decode(p, c, t, pos):
+            return forward(p, cfg, {"tokens": t}, mode="decode", cache=c,
+                           pos=pos)
+
         # the cache is donated: each step updates it in place on the device
-        self._decode = jax.jit(
-            lambda p, c, t, pos: forward(p, cfg, {"tokens": t},
-                                         mode="decode", cache=c, pos=pos),
-            donate_argnums=(1,))
+        self._decode = jax.jit(engine_decode, donate_argnums=(1,))
 
     # -- admission ----------------------------------------------------------
     def submit(self, req: ServeRequest) -> None:
@@ -89,17 +102,26 @@ class ServingEngine:
             self.slot_tok[slot, 0] = req.prompt[0]
 
     # -- stepping -----------------------------------------------------------
-    def step(self, now: float = 0.0) -> int:
+    def step(self) -> int:
         """Admit + one fixed-shape decode step.  Returns #active slots."""
-        self._admit()
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
+        phase = self.phases.phase
+        with phase("engine.admit"):
+            self._admit()
+            active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return 0
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.slot_tok),
-            jnp.asarray(self.slot_pos))
+        with phase("engine.launch"):
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.slot_tok),
+                jnp.asarray(self.slot_pos))
         self.steps += 1
-        logits = np.asarray(logits[:, :self.cfg.vocab_size])
+        with phase("engine.readback"):
+            logits = np.asarray(logits[:, :self.cfg.vocab_size])
+        with phase("engine.sample"):
+            self._sample(active, logits)
+        return len(active)
+
+    def _sample(self, active: list[int], logits: np.ndarray) -> None:
         for slot in active:
             req = self.slot_req[slot]
             self.slot_pos[slot] += 1
@@ -118,11 +140,9 @@ class ServingEngine:
                         and nxt == self.ecfg.eos_id)
                     or self.slot_pos[slot] >= self.ecfg.kv_capacity - 1)
             if done:
-                req.done_at = now
                 self.finished.append(req)
                 self.slot_req[slot] = None
                 self.slot_pos[slot] = 0
-        return len(active)
 
     def drain(self, max_steps: int = 100_000) -> None:
         while self.waiting or any(r is not None for r in self.slot_req):

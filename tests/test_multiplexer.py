@@ -60,3 +60,79 @@ def test_eviction_on_persistent_violation():
                     MuxConfig(slo_slowdown=1.2, evict_after_violations=10))
     s = m.run(arrivals(50, 300), 10.0)
     assert s.evicted
+
+
+def test_offline_duty_is_offline_time_over_the_clock():
+    m = make_mux()
+    s = m.run(arrivals(20, 100), 6.0)
+    off = sum(st.end - st.start for st in m.steps if st.kind == "offline")
+    assert s.offline_steps > 0
+    assert s.offline_duty == pytest.approx(off / m.steps[-1].end, rel=1e-12)
+
+
+def test_equal_arrival_times_are_served_in_id_order():
+    arr = [0.0, 0.0, 0.0, 0.05, 0.05, 0.05, 0.05, 0.05, 0.05, 0.05, 0.05,
+           0.05, 0.3, 0.3]
+    m = make_mux()
+    s = m.run(arr, 1.0)
+    assert s.served == len(arr)
+    served = sorted(m.requests, key=lambda r: (r.done, r.step))
+    assert [r.request_id for r in served] == list(range(len(arr)))
+    # nine requests at 0.05 leave one for the next step (max_batch 8)
+    assert len({r.step for r in m.requests if r.arrival == 0.05}) == 2
+
+
+def test_mux_config_has_no_telemetry_interval():
+    with pytest.raises(TypeError):
+        MuxConfig(telemetry_interval_s=0.1)
+
+
+@pytest.mark.parametrize("qps,horizon,max_off", [(40, 8.0, None),
+                                                 (0, 2.0, 5),
+                                                 (150, 4.0, None)])
+def test_steps_tile_the_clock(qps, horizon, max_off):
+    m = make_mux()
+    arr = arrivals(qps, int(qps * horizon * 0.8)) if qps else []
+    s = m.run(arr, horizon, max_offline_steps=max_off)
+    assert m.steps and m.steps[0].start == 0.0
+    for a, b in zip(m.steps, m.steps[1:]):
+        assert b.start == a.end
+    assert m.steps[-2].end < horizon <= m.steps[-1].end
+    kinds = {st.kind for st in m.steps}
+    assert kinds <= {"online", "offline", "idle"}
+    assert sum(st.kind == "offline" for st in m.steps) == s.offline_steps
+    online = [st for st in m.steps if st.kind == "online"]
+    assert sum(st.batch for st in online) == s.served
+    assert all(0.0 < st.batch <= m.cfg.max_batch for st in online)
+    assert all(0.0 <= st.duty <= 0.95 for st in m.steps)
+
+
+def test_requests_once_in_arrival_order_done_at_their_step_end():
+    arr = arrivals(60, 400, seed=3)
+    m = make_mux()
+    s = m.run(arr, 8.0)
+    assert [r.request_id for r in m.requests] == list(range(len(arr)))
+    assert [r.arrival for r in m.requests] == sorted(arr)
+    served = [r for r in m.requests if r.done is not None]
+    assert len(served) == s.served
+    for r in served:
+        st = m.steps[r.step]
+        assert st.kind == "online"
+        assert r.done == st.end and r.arrival <= st.start
+    assert [r.latency for r in served] == m._latencies
+    # each online step served exactly its batch
+    per_step = {}
+    for r in served:
+        per_step[r.step] = per_step.get(r.step, 0) + 1
+    assert per_step == {k: st.batch for k, st in enumerate(m.steps)
+                        if st.kind == "online"}
+
+
+def test_loop_control_is_one_span_per_part():
+    m = make_mux()
+    m.run(arrivals(40, 200), 5.0)
+    n_online = sum(st.kind == "online" for st in m.steps)
+    # one span per iteration, and one more after each online step (the PID
+    # update and violation count follow the step)
+    assert m.phases.calls["mux.control"] == len(m.steps) + n_online
+    assert set(m.phases.calls) == {"mux.control"}

@@ -68,3 +68,45 @@ def test_slot_reuse_and_fixed_shape(setup):
     assert len(eng.finished) == 5
     # one compiled program: decode was jitted once; steps bounded
     assert eng.steps < 5 * (3 + 3) + 10
+
+
+SPANS = ("engine.admit", "engine.launch", "engine.readback", "engine.sample")
+
+
+def test_each_step_records_its_four_spans(setup):
+    cfg, params = setup
+    rng = np.random.default_rng(3)
+    eng = ServingEngine(cfg, params, EngineConfig(num_slots=2, kv_capacity=64))
+    for i in range(3):
+        eng.submit(ServeRequest(i, rng.integers(0, cfg.vocab_size, 4)
+                                .astype(np.int32), max_new_tokens=3))
+    n = 0
+    while eng.waiting or eng.active_slots:
+        assert eng.step() > 0
+        n += 1
+    assert n == eng.steps
+    assert {k: eng.phases.calls[k] for k in SPANS} == {k: n for k in SPANS}
+    for k in SPANS:
+        assert 0.0 <= eng.phases.longest[k] <= eng.phases.totals[k]
+    # a step with nothing to run admits and stops there
+    assert eng.step() == 0
+    assert eng.phases.calls["engine.admit"] == n + 1
+    assert eng.phases.calls["engine.launch"] == n
+
+
+def test_step_takes_no_clock_and_requests_carry_no_done_time(setup):
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, EngineConfig(num_slots=1, kv_capacity=64))
+    with pytest.raises(TypeError):
+        eng.step(now=1.0)
+    req = ServeRequest(0, np.zeros(2, np.int32), max_new_tokens=1)
+    assert "done_at" not in {f.name for f in dataclasses.fields(req)}
+
+
+def test_decode_program_has_a_stable_name(setup):
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, EngineConfig(num_slots=1, kv_capacity=64))
+    lowered = eng._decode.lower(eng.params, eng.cache,
+                                jnp.asarray(eng.slot_tok),
+                                jnp.asarray(eng.slot_pos))
+    assert "jit_engine_decode" in lowered.as_text()
