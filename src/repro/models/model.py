@@ -335,10 +335,13 @@ def _apply_cross_attn(bp, x, enc_out, cfg, cache, mode):
 
 
 def _apply_block(bp, x, cfg: ModelConfig, desc, positions, cache, mode,
-                 enc_out=None):
-    """Returns (x, new_cache, aux_loss)."""
+                 enc_out=None, moe_counts=False):
+    """Returns (x, new_cache, aux_loss, counts): counts are the MoE
+    dispatch's (see `forward`) where `moe_counts` and the block has one,
+    else None."""
     mixer, f = desc
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     new_cache: dict = {}
     h = L.rmsnorm(bp["norm1"], x)
     if mixer in ("attn", "attn_cross"):
@@ -382,10 +385,13 @@ def _apply_block(bp, x, cfg: ModelConfig, desc, positions, cache, mode,
         x = x + L.ffn(bp["ffn"], h, cfg.ffn_act)
     elif f == "moe":
         h = L.rmsnorm(bp["norm2"], x)
-        o, a = M.moe_ffn(bp["ffn"], h, cfg)
+        if moe_counts:
+            o, a, counts = M.moe_ffn(bp["ffn"], h, cfg, counts=True)
+        else:
+            o, a = M.moe_ffn(bp["ffn"], h, cfg)
         x = x + o
         aux = aux + a
-    return x, new_cache, aux
+    return x, new_cache, aux, counts
 
 
 # ===========================================================================
@@ -426,13 +432,18 @@ def _encoder_forward(params, cfg: ModelConfig, src_embeds):
 
 
 def forward(params, cfg: ModelConfig, batch: dict, *, mode: str = "train",
-            cache: tuple | None = None, pos=None):
+            cache: tuple | None = None, pos=None, moe_counts: bool = False):
     """Unified forward.
 
     train:   batch={tokens,(src_embeds|patch_embeds)} -> (logits, aux)
     prefill: same batch -> (last_logits, cache, aux)
-    decode:  batch={tokens (B,1)}, cache, pos -> (logits, cache)
+    decode:  batch={tokens (B,1)}, cache, pos -> (logits, cache), and with
+             `moe_counts` (logits, cache, counts): int32 (MoE layers, 2), for
+             each MoE layer in depth order the distinct experts the batch's
+             tokens were routed to and the routed slots the dispatch dropped
     """
+    if moe_counts and mode != "decode":
+        raise ValueError("moe_counts is read in decode mode only")
     enc_out = None
     if cfg.enc_layers and mode != "decode":
         enc_out = _encoder_forward(params, cfg, batch["src_embeds"])
@@ -451,13 +462,18 @@ def forward(params, cfg: ModelConfig, batch: dict, *, mode: str = "train",
         x, aux = carry
         blocks = xs[0]
         caches = xs[1] if cache is not None else (None,) * P
-        new_caches = []
+        new_caches, counts = [], []
         for i, desc in enumerate(cfg.pattern):
-            x, nc, a = _apply_block(blocks[i], x, cfg, desc, positions,
-                                    caches[i], mode, enc_out=enc_out)
+            x, nc, a, c = _apply_block(blocks[i], x, cfg, desc, positions,
+                                       caches[i], mode, enc_out=enc_out,
+                                       moe_counts=moe_counts)
             x = constrain(x, "dp", None, None)
             new_caches.append(nc)
             aux = aux + a
+            if c is not None:
+                counts.append(c)
+        if moe_counts:
+            return (x, aux), (tuple(new_caches), tuple(counts))
         return (x, aux), tuple(new_caches)
 
     body = superblock
@@ -466,6 +482,9 @@ def forward(params, cfg: ModelConfig, batch: dict, *, mode: str = "train",
 
     xs = (params["blocks"],) if cache is None else (params["blocks"], cache)
     (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    if moe_counts:
+        new_cache, counts = new_cache      # each (repeats, 2): to depth order
+        counts = jnp.stack(counts, 1).reshape(-1, 2)
     x = L.rmsnorm(params["final_norm"], x)
 
     if mode == "train":
@@ -478,4 +497,6 @@ def forward(params, cfg: ModelConfig, batch: dict, *, mode: str = "train",
         logits = last @ params["lm_head"]
         return logits[:, 0], new_cache, aux
     logits = x[:, 0] @ params["lm_head"]
+    if moe_counts:
+        return logits, new_cache, counts
     return logits, new_cache

@@ -6,8 +6,9 @@ Two execution paths:
     trivially shardable; this is the paper-faithful baseline the roofline
     analysis starts from.
   * `moe_grouped_dispatch` — capacity-based gather/scatter dispatch: tokens are
-    routed to per-expert buffers of capacity C = ceil(k*T/E)*cf, experts run
-    only on their buffers.  This is the optimized path (§Perf).
+    routed to per-expert buffers of capacity C = ceil(k*T/E)*cf (C = 1 at
+    T = 1, which drops nothing), experts run only on their buffers.  This is
+    the optimized path (§Perf).
 """
 from __future__ import annotations
 
@@ -77,7 +78,8 @@ def moe_dense_dispatch(params, x, cfg):
     return y, aux
 
 
-def moe_grouped_dispatch(params, x, cfg, capacity_factor: float = 1.25):
+def moe_grouped_dispatch(params, x, cfg, capacity_factor: float = 1.25,
+                         counts: bool = False):
     """Capacity-based grouped dispatch (production path, expert-parallel).
 
     Each batch row is a dispatch *group* (stays on its data shard).  Within a
@@ -85,14 +87,21 @@ def moe_grouped_dispatch(params, x, cfg, capacity_factor: float = 1.25):
     O(M log M) instead of the O(M·E) cumsum, scattered into per-expert
     capacity buffers, run through the expert FFN (experts sharded over the
     model axis = EP), and gathered back.  Slots beyond capacity are dropped
-    (GShard/Switch semantics).
+    (GShard/Switch semantics); at S = 1 (decode) none can be.
+
+    With `counts`, also returns int32 [distinct experts the tokens of all
+    rows were routed to, routed slots dropped].
     """
     B, S, d = x.shape
     E, K = cfg.num_experts, cfg.top_k
     M = S * K
     weights, idx, aux = router_probs(params, x, cfg)             # (B,S,K)
-    cap = int(max(1, round(-(-S * K // E) * capacity_factor)))
-    cap = min(cap, M)
+    if S == 1:
+        # decode: a token's top-k experts are distinct, so no expert takes
+        # more than one of a row's slots, and capacity 1 drops none
+        cap = 1
+    else:
+        cap = min(int(max(1, round(-(-M // E) * capacity_factor))), M)
 
     flat_idx = idx.reshape(B, M)                                 # expert of slot
     tok_of_slot = jnp.repeat(jnp.arange(S), K)                   # (M,)
@@ -126,7 +135,10 @@ def moe_grouped_dispatch(params, x, cfg, capacity_factor: float = 1.25):
     if "shared" in params:
         from .layers import ffn
         y = y + ffn(params["shared"], x, cfg.ffn_act)
-    return y.astype(x.dtype), aux
+    if not counts:
+        return y.astype(x.dtype), aux
+    hit = jnp.zeros((E,), jnp.int32).at[flat_idx.reshape(-1)].set(1).sum()
+    return y.astype(x.dtype), aux, jnp.stack([hit, jnp.sum(~keep, dtype=jnp.int32)])
 
 
 def _expert_ffn_grouped(wp, buf, act):
@@ -246,9 +258,16 @@ def moe_a2a_dispatch(params, x, cfg, capacity_factor: float = 1.25):
     return y, jnp.mean(aux)
 
 
-def moe_ffn(params, x, cfg):
-    """Dispatch-mode switch: cfg.moe_impl in {'dense','grouped','a2a'}."""
+def moe_ffn(params, x, cfg, counts: bool = False):
+    """Dispatch-mode switch: cfg.moe_impl in {'dense','grouped','a2a'}.
+    `counts` (grouped only) adds the dispatch's counts to what it returns."""
     impl = getattr(cfg, "moe_impl", "dense")
+    if counts:
+        if impl != "grouped":
+            raise ValueError(f"MoE counts come from the grouped dispatch, "
+                             f"not {impl!r}")
+        return moe_grouped_dispatch(params, x, cfg, cfg.moe_capacity_factor,
+                                    counts=True)
     if impl == "a2a":
         return moe_a2a_dispatch(params, x, cfg,
                                 capacity_factor=cfg.moe_capacity_factor)

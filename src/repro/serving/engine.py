@@ -26,6 +26,20 @@ host span in a JAX profiler trace, see ``repro.obs.phases``):
   engine.readback  the logits to a host array: the wait for the device and
                    the copy
   engine.sample    the per-slot loop: argmax, bookkeeping, retirement
+
+For a model with MoE layers the engine also counts, per MoE layer, over its
+decode steps (``moe_counts()``; ``reset_moe_counts()`` starts them again, as
+``phases.reset()`` does the spans):
+
+  experts_hit      the distinct experts the step's tokens were routed to
+                   (every slot's row runs and counts, idle ones included)
+  dropped          routed slots the grouped dispatch dropped (0 by
+                   construction at one token a slot)
+  steps            the decode steps counted
+
+The totals accumulate on the device, in the decode's donated state beside
+the cache (``self.cache`` is then ``(model cache, totals)``); a step moves
+nothing more to the host, and a model with no MoE layer decodes as before.
 """
 from __future__ import annotations
 
@@ -77,10 +91,20 @@ class ServingEngine:
         self.finished: list[ServeRequest] = []
         self.steps = 0
         self.phases = PhaseProfiler()
+        moe = cfg.repeats * sum(f == "moe" for _, f in cfg.pattern)
+        self._moe_layers = moe
+        if moe:
+            self.cache = (self.cache, jnp.zeros((moe, 3), jnp.int32))
 
         def engine_decode(p, c, t, pos):
-            return forward(p, cfg, {"tokens": t}, mode="decode", cache=c,
-                           pos=pos)
+            if not moe:
+                return forward(p, cfg, {"tokens": t}, mode="decode", cache=c,
+                               pos=pos)
+            c, totals = c
+            logits, c, counts = forward(p, cfg, {"tokens": t}, mode="decode",
+                                        cache=c, pos=pos, moe_counts=True)
+            step = jnp.ones((moe, 1), jnp.int32)
+            return logits, (c, totals + jnp.concatenate([counts, step], 1))
 
         # the cache is donated: each step updates it in place on the device
         self._decode = jax.jit(engine_decode, donate_argnums=(1,))
@@ -150,6 +174,19 @@ class ServingEngine:
             max_steps -= 1
             if max_steps <= 0:
                 raise RuntimeError("engine did not drain")
+
+    def moe_counts(self) -> dict | None:
+        """The MoE counters since the last reset, per MoE layer in depth
+        order: {"experts_hit", "dropped", "steps"} of int arrays; None for a
+        model with no MoE layer.  Waits for the last step and copies."""
+        if not self._moe_layers:
+            return None
+        t = np.asarray(self.cache[1])
+        return {"experts_hit": t[:, 0], "dropped": t[:, 1], "steps": t[:, 2]}
+
+    def reset_moe_counts(self) -> None:
+        if self._moe_layers:
+            self.cache = (self.cache[0], jnp.zeros_like(self.cache[1]))
 
     @property
     def active_slots(self) -> int:
