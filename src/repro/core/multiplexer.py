@@ -9,7 +9,7 @@ microsteps).  The offline duty fraction plays the SM-percentage role:
   * the MemoryQuota ledger enforces the offline HBM quota before the offline
     state is ever allocated,
   * GracefulExit freezes offline launches and checkpoints on SIGINT/SIGTERM,
-  * an SLO guard (latency-based eviction) mirrors SysMonitor's Overlimit.
+  * an SLO guard (SysMonitor's Overlimit) freezes them too; serving goes on.
 
 The loop runs on the multiplexer's clock: time advances by the durations
 the step functions return and by idle quanta, never by the loop's own host
@@ -63,7 +63,7 @@ class MuxConfig:
     slo_slowdown: float = 1.2        # protect online latency to <= 1.2x base
     max_batch: int = 8               # online serving batch cap
     quantum_s: float = 0.010         # scheduling quantum (one decode step)
-    evict_after_violations: int = 50  # SysMonitor-style overlimit -> evict
+    evict_after_violations: int = 50  # late requests -> evict offline job
     latency_budget_s: float | None = None   # absolute end-to-end budget
     quota_frac: float = 0.4
     # stand-in for backends that report no memory; callers on an
@@ -150,7 +150,6 @@ class Multiplexer:
                     batch = [heapq.heappop(queue)[2]
                              for _ in range(min(cfg.max_batch, len(queue)))]
                     launch = (not batch
-                              and not self.throttle.frozen
                               and self.throttle.should_launch(cfg.quantum_s)
                               and (max_offline_steps is None
                                    or offline_steps < max_offline_steps))
@@ -172,9 +171,10 @@ class Multiplexer:
                             slowdown, dt)
                         steps.append(Step("online", start, t, len(batch),
                                           self.throttle.duty))
-                        if self._violations >= cfg.evict_after_violations:
+                        if (self._violations >= cfg.evict_after_violations
+                                and not self.stats.evicted):
                             self.stats.evicted = True   # SysMonitor Overlimit
-                            break
+                            self.throttle.freeze()      # evict offline job
                 elif launch:
                     dt = self.offline_fn()
                     t += dt

@@ -1,7 +1,10 @@
 """On-device multiplexer: SLO protection, quota, graceful exit, eviction."""
+import signal
+
 import numpy as np
 import pytest
 
+from repro.core.errors import ErrorKind, GracefulExit
 from repro.core.multiplexer import Multiplexer, MuxConfig
 from repro.core.protection import QuotaExceeded
 
@@ -60,6 +63,85 @@ def test_eviction_on_persistent_violation():
                     MuxConfig(slo_slowdown=1.2, evict_after_violations=10))
     s = m.run(arrivals(50, 300), 10.0)
     assert s.evicted
+    # eviction takes the offline job away, not the service
+    assert s.served == 300 and all(r.done is not None for r in m.requests)
+    assert m.throttle.frozen
+    assert s.slo_violations == 300            # still counted after eviction
+    # every request is late (0.05 s > the 0.048 s budget): the tenth one
+    # served reached the limit, and no offline step starts after its step
+    tenth = sorted(m.requests, key=lambda r: (r.step, r.request_id))[9]
+    assert all(st.kind != "offline" for st in m.steps[tenth.step + 1:])
+
+
+def _frozen_run(freeze):
+    """A run whose offline job is frozen mid-run by `freeze` ("evict": the
+    SLO guard, "graceful": SIGTERM through GracefulExit, None: no freeze),
+    with the calls the loop makes logged in order as the benchmark logs
+    them.  Returns the multiplexer, its stats, the arrivals, the log and
+    the number of steps when the throttle froze."""
+    # a budget a short queue wait already misses: late requests pile up
+    # while the measured slowdown stays under the SLO and offline runs on
+    cfg = MuxConfig(latency_budget_s=0.015,
+                    evict_after_violations=30 if freeze == "evict" else 10**9)
+    log, at = [], []
+
+    def online_fn(bs):
+        if (freeze == "graceful" and not at and m.steps
+                and m.steps[-1].end >= 1.0):
+            signal.raise_signal(signal.SIGTERM)
+        dt = 0.010 * (1.0 + 0.35 * m.throttle.duty)
+        log.append(("online", dt, bs))
+        return dt
+
+    def offline_fn():
+        log.append(("offline", 0.020))
+        return 0.020
+
+    m = Multiplexer(online_fn, offline_fn, 0.010, 0.020, cfg)
+    m.graceful = GracefulExit()
+    gate, freeze_fn = m.throttle.should_launch, m.throttle.freeze
+
+    def observed_gate(quantum=1.0):
+        ok = gate(quantum)
+        log.append(("gate", quantum))
+        return ok
+
+    def observed_freeze():
+        at.append(len(m.steps))
+        freeze_fn()
+
+    m.throttle.should_launch, m.throttle.freeze = observed_gate, observed_freeze
+    arr = arrivals(40, 160, seed=1)
+    s = m.run(arr, 5.0)
+    return m, s, arr, log, at[0] if at else None
+
+
+@pytest.mark.parametrize("freeze", ["evict", "graceful"])
+def test_a_frozen_offline_job_leaves_serving_and_the_clock_whole(freeze):
+    m, s, arr, log, n = _frozen_run(freeze)
+    assert n is not None and m.throttle.frozen
+    assert s.evicted == (freeze == "evict")
+    assert (m.graceful.triggered == ErrorKind.SIGTERM) == (freeze == "graceful")
+    assert s.served == len(arr) and all(r.done is not None for r in m.requests)
+    # offline ran before the freeze and would have run on without it
+    assert any(st.kind == "offline" for st in m.steps[:n])
+    assert all(st.kind != "offline" for st in m.steps[n:])
+    ctrl = _frozen_run(None)[0]
+    assert any(st.kind == "offline" for st in ctrl.steps[n:])
+    # the clock rebuilt from the logged calls: each gate call that no
+    # offline step follows is an idle quantum
+    t, clock = 0.0, []
+    for i, ev in enumerate(log):
+        if ev[0] == "gate":
+            if i + 1 < len(log) and log[i + 1][0] == "offline":
+                continue
+            ev = ("idle", ev[1], 0)
+        start, t = t, t + ev[1]
+        clock.append((ev[0], start, t, ev[2] if ev[0] == "online" else 0))
+    assert clock == [(st.kind, st.start, st.end, st.batch) for st in m.steps]
+    assert m.steps[0].start == 0.0
+    assert all(b.start == a.end for a, b in zip(m.steps, m.steps[1:]))
+    assert m.steps[-2].end < 5.0 <= m.steps[-1].end
 
 
 def test_offline_duty_is_offline_time_over_the_clock():
